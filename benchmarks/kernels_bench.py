@@ -44,11 +44,14 @@ def run(quick: bool = False):
     q = jax.random.normal(key, (B, H, hd), jnp.float32)
     k = jax.random.normal(key, (B, S, KV, hd), jnp.float32)
     v = jax.random.normal(key, (B, S, KV, hd), jnp.float32)
+    # the kernel reads kv-head-major caches; lay them out before timing
+    k_t, v_t = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     lens = jnp.full((B,), S, jnp.int32)
     flops = 4 * B * H * hd * S
     rows.append({
         "name": "decode_attention/pallas-interpret",
-        "us_per_call": round(_time(decode_attention_pallas, q, k, v, lens), 1),
+        "us_per_call": round(_time(decode_attention_pallas, q, k_t, v_t,
+                                   lens), 1),
         "derived_flops": flops,
     })
     rows.append({
@@ -65,8 +68,8 @@ def run(quick: bool = False):
     rng = np.random.default_rng(0)
     perm = rng.permutation(np.arange(1, P))[: B * n_pages]
     pt = jnp.asarray(perm.reshape(B, n_pages).astype(np.int32))
-    k_arena = jax.random.normal(key, (P, ps, KV, hd), jnp.float32)
-    v_arena = jax.random.normal(key, (P, ps, KV, hd), jnp.float32)
+    k_arena = jax.random.normal(key, (P, KV, ps, hd), jnp.float32)
+    v_arena = jax.random.normal(key, (P, KV, ps, hd), jnp.float32)
     flops = 4 * B * H * hd * S
     rows.append({
         "name": "paged_decode_attention/pallas-interpret",

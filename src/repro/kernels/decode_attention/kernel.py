@@ -4,10 +4,10 @@ prefill).
 
 One online-softmax accumulation over sequence blocks serves three callers:
 
-* contiguous decode — ``k_cache/v_cache [B, S, KV, hd]``: the grid iterates
-  (batch, kv_head, seq_block) and each program consumes one ``[block_s, hd]``
-  cache tile.
-* paged decode — ``k_arena/v_arena [num_pages, page_size, KV, hd]`` plus a
+* contiguous decode — ``k_cache/v_cache [B, KV, S, hd]``: the grid
+  iterates (batch, kv_head, seq_block) and each program consumes one
+  ``[block_s, hd]`` cache tile.
+* paged decode — ``k_arena/v_arena [num_pages, KV, page_size, hd]`` plus a
   per-row ``page_table [B, n_pages]`` of physical page ids: the grid's
   seq-block axis indexes *through the page table* (one program per logical
   page) using Pallas scalar prefetch, so the same online-softmax
@@ -25,12 +25,24 @@ One online-softmax accumulation over sequence blocks serves three callers:
 TPU adaptation (vs a CUDA warp-per-row decode kernel): each program instance
 processes a whole ``[BS, hd]`` cache tile from VMEM against the query tile
 (``[G, hd]`` for decode, ``[block_q * G, hd]`` for append) on the MXU, with
-running max / sum-exp / weighted-value accumulators in VMEM scratch. hd is
-kept at a 128-lane multiple and BS at a multiple of 8 for the VPU/MXU
-layout. Masking uses per-row valid lengths/positions; probabilities AND
-values are zeroed outside them, so out-of-bounds tile padding (NaN in
-interpret mode, garbage on TPU) and fully-masked rows (defined to return
-zeros) never reach the accumulators.
+running max / sum-exp / weighted-value accumulators in VMEM scratch. A
+tile spans the whole head dim (64 for qwen2-0.5b, 128 for qwen2-72b) and BS
+is a multiple of 8 for the VPU/MXU layout. Masking uses per-row valid
+lengths/positions; probabilities AND values are zeroed outside them, so
+out-of-bounds tile padding (NaN in interpret mode, garbage on TPU) and
+fully-masked rows (defined to return zeros) never reach the accumulators.
+
+Two layout rules of the TPU compiler shape every operand here:
+
+* the last two dims of a block must equal the array's or be (8, 128)
+  multiples. A k/v block is therefore ``[.., page_size/block_s, hd]`` with
+  the kv-head axis squeezed OUTSIDE those two dims — the arena is laid out
+  ``[P, KV, page_size, hd]`` and the contiguous cache ``[B, KV, S, hd]``.
+  Squeezing KV out of ``[.., KV, hd]`` would leave a block of 1 row where
+  the array has KV (refused for KV > 1).
+* a rank-1 block must cover the array or be a 128-multiple, so per-row
+  lengths cannot ride in as ``(1,)`` VMEM blocks. Lengths, like page
+  tables, are scalar-prefetch operands (SMEM) read with the program id.
 """
 from __future__ import annotations
 
@@ -80,7 +92,7 @@ def _flash_decode_body(len_ref, q_ref, k_ref, v_ref, o_ref,
     q_ref:   [G, hd]      (this batch row, this kv head's query group)
     k_ref:   [block_s, hd]
     v_ref:   [block_s, hd]
-    len_ref: [1]          (valid cache length for this row)
+    len_ref: [B]          (SMEM: valid cache length of every row)
     o_ref:   [G, hd]
     scratch: m_ref [G, 1], l_ref [G, 1], acc_ref [G, hd]  (f32)
 
@@ -103,7 +115,7 @@ def _flash_decode_body(len_ref, q_ref, k_ref, v_ref, o_ref,
     v = v_ref[...].astype(jnp.float32)
 
     tile_start = s_idx * block_s
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     # zero cache-value rows beyond the valid length BEFORE they can meet the
     # accumulators: tile padding past the array end is undefined (NaN in
     # interpret mode) and 0 * NaN would poison the p @ v product
@@ -135,14 +147,18 @@ def _paged_decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
                             block_s: int = 256, interpret: bool = True):
-    """q [B,H,hd]; k_cache/v_cache [B,S,KV,hd]; lengths [B] -> [B,H,hd].
+    """q [B,H,hd]; k_cache/v_cache [B,KV,S,hd] (kv-head-major); lengths [B]
+    -> [B,H,hd].
 
     ``block_s`` is clamped to cover S at the 8-multiple VPU/MXU layout
     constraint; a cache shorter than the block therefore runs a single
-    (padded, masked) program instead of a zero-size grid.
+    (padded, masked) program instead of a zero-size grid. The caches come
+    kv-head-major so each tile's last two dims are ``(block_s, hd)``; the
+    model's contiguous lanes are ``[B, S, KV, hd]`` and decode through the
+    jnp path, so this kernel serves no engine step.
     """
     B, H, hd = q.shape
-    S, KV = k_cache.shape[1], k_cache.shape[2]
+    KV, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     block_s = max(8, min(_round_up(block_s, 8), _round_up(S, 8)))
     scale = 1.0 / (hd ** 0.5)
@@ -150,28 +166,31 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
     qg = q.reshape(B, KV, G, hd)
     lengths = lengths.astype(jnp.int32)
 
-    grid = (B, KV, -(-S // block_s))     # ceil: ragged tail tile is masked
     kernel = functools.partial(_flash_decode_body, block_s=block_s,
                                scale=scale)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                       # the lengths
+        grid=(B, KV, -(-S // block_s)),  # ceil: ragged tail tile is masked
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),                      # len
-            pl.BlockSpec((None, None, G, hd), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((None, block_s, None, hd),
-                         lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((None, block_s, None, hd),
-                         lambda b, h, s: (b, s, h, 0)),
+            pl.BlockSpec((None, None, G, hd),
+                         lambda b, h, s, lens: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, block_s, hd),
+                         lambda b, h, s, lens: (b, h, s, 0)),
+            pl.BlockSpec((None, None, block_s, hd),
+                         lambda b, h, s, lens: (b, h, s, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, G, hd),
-                               lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+                               lambda b, h, s, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),       # running max
             pltpu.VMEM((G, 1), jnp.float32),       # running sum-exp
             pltpu.VMEM((G, hd), jnp.float32),      # running weighted values
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
     )(lengths, qg, k_cache, v_cache)
     return out.reshape(B, H, hd)
@@ -180,17 +199,18 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(q, k_arena, v_arena, page_table, lengths, *,
                                   interpret: bool = True):
-    """Paged flash-decode: q [B,H,hd]; arenas [P, page_size, KV, hd];
+    """Paged flash-decode: q [B,H,hd]; arenas [P, KV, page_size, hd];
     page_table [B, n_pages] int32 physical page ids; lengths [B] -> [B,H,hd].
 
-    One program per (row, kv_head, logical page). The page table rides in as
-    a scalar-prefetch operand so the k/v BlockSpec index maps can chase it:
-    program (b, h, i) DMAs physical page ``page_table[b, i]``. Entries past a
-    row's valid length may point anywhere (allocators pad with a trash page)
-    — they are masked by ``lengths`` exactly like the contiguous tail.
+    One program per (row, kv_head, logical page). The page table and the
+    lengths ride in as scalar-prefetch operands so the k/v BlockSpec index
+    maps can chase the table: program (b, h, i) DMAs head h of physical page
+    ``page_table[b, i]``. Entries past a row's valid length may point
+    anywhere (allocators pad with a trash page) — they are masked by
+    ``lengths`` exactly like the contiguous tail.
     """
     B, H, hd = q.shape
-    P, page_size, KV, _ = k_arena.shape
+    P, KV, page_size, _ = k_arena.shape
     n_pages = page_table.shape[1]
     G = H // KV
     scale = 1.0 / (hd ** 0.5)
@@ -202,19 +222,18 @@ def paged_decode_attention_pallas(q, k_arena, v_arena, page_table, lengths, *,
     kernel = functools.partial(_paged_decode_attn_kernel,
                                page_size=page_size, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                       # the page table
+        num_scalar_prefetch=2,                       # page table, lengths
         grid=(B, KV, n_pages),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, i, pt: (b,)),                  # len
             pl.BlockSpec((None, None, G, hd),
-                         lambda b, h, i, pt: (b, h, 0, 0)),
-            pl.BlockSpec((None, page_size, None, hd),
-                         lambda b, h, i, pt: (pt[b, i], 0, h, 0)),
-            pl.BlockSpec((None, page_size, None, hd),
-                         lambda b, h, i, pt: (pt[b, i], 0, h, 0)),
+                         lambda b, h, i, pt, lens: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, page_size, hd),
+                         lambda b, h, i, pt, lens: (pt[b, i], h, 0, 0)),
+            pl.BlockSpec((None, None, page_size, hd),
+                         lambda b, h, i, pt, lens: (pt[b, i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, G, hd),
-                               lambda b, h, i, pt: (b, h, 0, 0)),
+                               lambda b, h, i, pt, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -245,7 +264,7 @@ def _paged_append_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     query position (shared prefix + already-written suffix); q rows past the
     valid suffix have position >= total_len and mask out entirely (their
     output is the defined zero and the engine never reads them).
-    len_ref: [2] = (prefix_len, total_len = prefix_len + suffix_len).
+    len_ref: [2] (SMEM) = (prefix_len, total_len = prefix_len + suffix_len).
     """
     i = pl.program_id(2)
     n_i = pl.num_programs(2)
@@ -290,9 +309,10 @@ def paged_append_attention_pallas(q, k_arena, v_arena, page_table, lens, *,
 
     q [S, H, hd] — S suffix tokens (padded; multiple of 8) whose token i
     sits at absolute position ``prefix_len + i``; arenas
-    [P, page_size, KV, hd]; page_table [n_pages] int32 physical page ids for
+    [P, KV, page_size, hd]; page_table [n_pages] int32 physical page ids for
     ONE request (batch-1 admission path); lens [2] int32 =
-    (prefix_len, total_len). Returns [S, H, hd].
+    (prefix_len, total_len), scalar-prefetched beside the page table.
+    Returns [S, H, hd].
 
     The grid is (S / block_q, KV, n_pages): each program attends one
     ``[block_q * G, hd]`` query tile to one physical page, chasing the
@@ -303,7 +323,7 @@ def paged_append_attention_pallas(q, k_arena, v_arena, page_table, lens, *,
     at a multiple of 8.
     """
     S, H, hd = q.shape
-    _, page_size, KV, _ = k_arena.shape
+    _, KV, page_size, _ = k_arena.shape
     n_pages = page_table.shape[0]
     G = H // KV
     if S % 8:
@@ -327,19 +347,18 @@ def paged_append_attention_pallas(q, k_arena, v_arena, page_table, lens, *,
                                page_size=page_size, block_q=block_q,
                                group=G, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                       # the page table
+        num_scalar_prefetch=2,                       # page table, lens
         grid=(n_qc, KV, n_pages),
         in_specs=[
-            pl.BlockSpec((2,), lambda c, h, i, pt: (0,)),                  # lens
             pl.BlockSpec((None, None, block_q * G, hd),
-                         lambda c, h, i, pt: (h, c, 0, 0)),
-            pl.BlockSpec((None, page_size, None, hd),
-                         lambda c, h, i, pt: (pt[i], 0, h, 0)),
-            pl.BlockSpec((None, page_size, None, hd),
-                         lambda c, h, i, pt: (pt[i], 0, h, 0)),
+                         lambda c, h, i, pt, lens: (h, c, 0, 0)),
+            pl.BlockSpec((None, None, page_size, hd),
+                         lambda c, h, i, pt, lens: (pt[i], h, 0, 0)),
+            pl.BlockSpec((None, None, page_size, hd),
+                         lambda c, h, i, pt, lens: (pt[i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, block_q * G, hd),
-                               lambda c, h, i, pt: (h, c, 0, 0)),
+                               lambda c, h, i, pt, lens: (h, c, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q * G, 1), jnp.float32),
             pltpu.VMEM((block_q * G, 1), jnp.float32),

@@ -29,16 +29,19 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_s: int = 256):
 
     ``block_s`` is a tiling hint; the kernel clamps it to cover S at the
     8-multiple layout constraint, so S < block_s no longer collapses to a
-    zero-size sequence grid.
+    zero-size sequence grid. The kernel reads kv-head-major caches, so on
+    TPU both caches are first copied to ``[B, KV, S, hd]``; no engine step
+    calls this (contiguous lanes decode through ``layers.decode_attention``).
     """
     if _on_tpu():
-        return decode_attention_pallas(q, k_cache, v_cache, lengths,
+        return decode_attention_pallas(q, k_cache.transpose(0, 2, 1, 3),
+                                       v_cache.transpose(0, 2, 1, 3), lengths,
                                        block_s=block_s, interpret=False)
     return decode_attention_ref(q, k_cache, v_cache, lengths)
 
 
 def paged_decode_attention(q, k_arena, v_arena, page_table, lengths):
-    """Paged GQA flash-decode. q [B,H,hd]; arenas [P, page_size, KV, hd];
+    """Paged GQA flash-decode. q [B,H,hd]; arenas [P, KV, page_size, hd];
     page_table [B, n_pages] physical page ids; lengths [B]."""
     if _on_tpu():
         return paged_decode_attention_pallas(q, k_arena, v_arena, page_table,
@@ -53,7 +56,7 @@ def paged_append_attention(q, k_arena, v_arena, page_table, prefix_len,
     :func:`paged_decode_attention`, used by prefix-cached suffix prefill.
 
     q [S, H, hd] (suffix token i at absolute position ``prefix_len + i``);
-    arenas [P, page_size, KV, hd]; page_table [n_pages] for ONE request;
+    arenas [P, KV, page_size, hd]; page_table [n_pages] for ONE request;
     prefix_len / total_len int32 scalars (``total_len`` = prefix + valid
     suffix; padded q rows beyond it return zeros).
     """

@@ -27,21 +27,27 @@ def decode_attention_ref(q, k_cache, v_cache, lengths):
     return out.reshape(B, H, hd).astype(q.dtype)
 
 
+def gather_pages(arena, page_table):
+    """Logical view of paged KV: arena [P, KV, page_size, hd] and
+    page_table [..., n_pages] -> [..., n_pages * page_size, KV, hd], where
+    logical position ``t`` comes from ``arena[page_table[..., t //
+    page_size], :, t % page_size]``."""
+    _, KV, page_size, hd = arena.shape
+    g = jnp.swapaxes(arena[page_table], -3, -2)   # [..., n, ps, KV, hd]
+    return g.reshape(*page_table.shape[:-1],
+                     page_table.shape[-1] * page_size, KV, hd)
+
+
 def paged_decode_attention_ref(q, k_arena, v_arena, page_table, lengths):
     """Gather-based paged oracle.
 
-    q [B,H,hd]; arenas [P, page_size, KV, hd]; page_table [B, n_pages] of
-    physical page ids; lengths [B] -> [B,H,hd]. Logical position
-    ``t`` of row ``b`` lives at ``arena[page_table[b, t // page_size],
-    t % page_size]``; the gather materializes each row's logical
-    [n_pages * page_size, KV, hd] view and defers to the contiguous oracle.
+    q [B,H,hd]; arenas [P, KV, page_size, hd]; page_table [B, n_pages] of
+    physical page ids; lengths [B] -> [B,H,hd]. The gather materializes
+    each row's logical [n_pages * page_size, KV, hd] view
+    (:func:`gather_pages`) and defers to the contiguous oracle.
     """
-    B = q.shape[0]
-    _, page_size, KV, hd = k_arena.shape
-    n_pages = page_table.shape[1]
-    k = k_arena[page_table].reshape(B, n_pages * page_size, KV, hd)
-    v = v_arena[page_table].reshape(B, n_pages * page_size, KV, hd)
-    return decode_attention_ref(q, k, v, lengths)
+    return decode_attention_ref(q, gather_pages(k_arena, page_table),
+                                gather_pages(v_arena, page_table), lengths)
 
 
 def paged_append_attention_ref(q, k_arena, v_arena, page_table, prefix_len,
@@ -49,7 +55,7 @@ def paged_append_attention_ref(q, k_arena, v_arena, page_table, prefix_len,
     """Gather-based oracle for chunked suffix prefill against paged KV.
 
     q [S, H, hd] — suffix token i sits at absolute position
-    ``prefix_len + i``; arenas [P, page_size, KV, hd]; page_table [n_pages]
+    ``prefix_len + i``; arenas [P, KV, page_size, hd]; page_table [n_pages]
     physical page ids for one request; prefix_len/total_len scalars with
     ``total_len = prefix_len + valid_suffix``. The gather materializes the
     request's logical [n_pages * page_size, KV, hd] view (prefix pages
@@ -59,11 +65,9 @@ def paged_append_attention_ref(q, k_arena, v_arena, page_table, prefix_len,
     return zeros.
     """
     S, H, hd = q.shape
-    _, page_size, KV, _ = k_arena.shape
-    n_pages = page_table.shape[0]
-    T = n_pages * page_size
-    k = k_arena[page_table].reshape(T, KV, hd).astype(jnp.float32)
-    v = v_arena[page_table].reshape(T, KV, hd).astype(jnp.float32)
+    k = gather_pages(k_arena, page_table).astype(jnp.float32)  # [T, KV, hd]
+    v = gather_pages(v_arena, page_table).astype(jnp.float32)
+    T, KV = k.shape[0], k.shape[1]
     G = H // KV
     qg = q.reshape(S, KV, G, hd).astype(jnp.float32)
     qpos = prefix_len + jnp.arange(S)

@@ -130,11 +130,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
     mf = model_flops_estimate(cfg, shape)
     rl = roofline_from_compiled(compiled, chips, mf,
                                 pod_size=256 if multi_pod else chips)
-    mem_txt = ""
-    try:
-        mem_txt = str(compiled.memory_analysis())
-    except Exception as e:  # pragma: no cover
-        mem_txt = f"<unavailable: {e}>"
+    mem_txt = str(compiled.memory_analysis())
 
     res = {
         "status": "ok",

@@ -95,22 +95,10 @@ class Roofline:
 def roofline_from_compiled(compiled, chips: int, model_flops: float,
                            pod_size: int = 256) -> Roofline:
     cost = analyze_hlo(compiled.as_text(), pod_size=pod_size)
-    xla_flops = -1.0
-    try:
-        ca = compiled.cost_analysis() or {}
-        if isinstance(ca, list):
-            ca = ca[0] if ca else {}
-        xla_flops = float(ca.get("flops", -1.0))
-    except Exception:
-        pass
-    peak = -1.0
-    try:
-        ma = compiled.memory_analysis()
-        peak = float(getattr(ma, "temp_size_in_bytes", 0)
-                     + getattr(ma, "argument_size_in_bytes", 0)
-                     + getattr(ma, "output_size_in_bytes", 0))
-    except Exception:
-        pass
+    xla_flops = float(compiled.cost_analysis().get("flops", -1.0))
+    ma = compiled.memory_analysis()
+    peak = float(ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                 + ma.output_size_in_bytes)
     return Roofline(
         flops=cost.flops, bytes_accessed=cost.bytes,
         transcendentals=cost.transcendentals,

@@ -5,6 +5,12 @@ latter).
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
       --prompts "hello world" "what is rag"
 
+The arch runs as its reduced smoke variant unless ``--published`` asks for
+its published widths (qwen2-0.5b: 24 layers, d 896, vocab 151,936 — a
+chip-sized run). The persistent compilation cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache/`` at the
+repository root.
+
 The engine streams any number of prompts through a fixed pool of
 ``--max-batch`` slots backed by a block-granular paged KV-cache (page
 arena + per-slot page tables) wherever the arch supports it, with a
@@ -39,6 +45,7 @@ import argparse
 import time
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.scheduler import TierScheduler
 
@@ -46,6 +53,9 @@ from repro.serving.scheduler import TierScheduler
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
+    ap.add_argument("--published", action="store_true",
+                    help="serve the arch at its published widths instead "
+                         "of the reduced smoke variant")
     ap.add_argument("--max-seq", type=int, default=256)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=24)
@@ -103,7 +113,8 @@ def main():
                     default=["What is the capital of France?"])
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, reduced=True)
+    enable_compile_cache()
+    cfg = get_config(args.arch, reduced=not args.published)
     if cfg.vocab < 300:
         raise SystemExit("arch vocab too small for byte tokenizer")
     if args.static and (args.chaos or args.hedge_ms is not None
@@ -122,7 +133,8 @@ def main():
     kv = (f"paged KV: {eng.num_pages} x {eng.page_size}-token pages, "
           f"prefix cache {'on' if eng.prefix_cache_enabled else 'off'}"
           if eng.kv_layout == "paged" else "contiguous KV lanes")
-    print(f"serving {cfg.arch_id} (reduced, {eng.model.n_params():,} params, "
+    width = "published" if args.published else "reduced"
+    print(f"serving {cfg.arch_id} ({width}, {eng.model.n_params():,} params, "
           f"{kv}; random weights — output is noise; the engine is real)")
     reqs = [Request(p, max_new_tokens=args.max_new,
                     temperature=args.temperature, slo=args.slo_class)

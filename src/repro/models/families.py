@@ -52,11 +52,13 @@ def _kv_cache_defs(B: int, S: int, n_kv: int, hd: int, dtype=jnp.bfloat16,
 def _kv_arena_defs(P: int, ps: int, n_kv: int, hd: int, dtype=jnp.bfloat16):
     """Paged layout: one global page arena per layer instead of per-slot
     lanes. Logical position t of a request lives at
-    ``arena[page_table[slot, t // ps], t % ps]``."""
-    ax = ("pages", "page_seq", "kv_heads", None)
+    ``arena[page_table[slot, t // ps], :, t % ps]``. Pages are kv-head-major
+    (``[P, KV, ps, hd]``) so a kernel block of one head's page has
+    ``(ps, hd)`` as its last two dims, the tiling the TPU compiler takes."""
+    ax = ("pages", "kv_heads", "page_seq", None)
     return {
-        "k": ParamDef((P, ps, n_kv, hd), ax, dtype, init="zeros"),
-        "v": ParamDef((P, ps, n_kv, hd), ax, dtype, init="zeros"),
+        "k": ParamDef((P, n_kv, ps, hd), ax, dtype, init="zeros"),
+        "v": ParamDef((P, n_kv, ps, hd), ax, dtype, init="zeros"),
     }
 
 
@@ -167,7 +169,7 @@ def make_attn_layer(cfg: ModelConfig, *, window: int = 0, ffn: str = "dense",
         k = apply_rope(k, pos[:, None], theta)
         packed = _pack(k, v)
         if ctx.get("page_table") is not None:
-            # paged layout: ce leaves are [P, page_size, KV, hd] arenas;
+            # paged layout: ce leaves are [P, KV, page_size, hd] arenas;
             # scatter this token at its slot's physical (page, offset) and
             # attend through the page table. The allocator guarantees every
             # active slot owns distinct pages, so the scatter never races;
@@ -176,7 +178,7 @@ def make_attn_layer(cfg: ModelConfig, *, window: int = 0, ffn: str = "dense",
             pt = ctx["page_table"]                   # [B, n_pages] int32
             phys = jnp.take_along_axis(
                 pt, (pos // ps_sz)[:, None], axis=1)[:, 0]
-            new_ce = {name: ce[name].at[phys, pos % ps_sz].set(
+            new_ce = {name: ce[name].at[phys, :, pos % ps_sz].set(
                           packed[name][:, 0].astype(ce[name].dtype))
                       for name in packed}
             a = paged_decode_attention(q[:, 0], new_ce["k"], new_ce["v"],
@@ -226,7 +228,7 @@ def make_attn_layer(cfg: ModelConfig, *, window: int = 0, ffn: str = "dense",
             phys = jnp.where(jnp.arange(S) < suffix_len,
                              pt[pos // ps_sz], 0)    # padding -> trash page
             off = pos % ps_sz
-            new_ce = {name: ce[name].at[phys, off].set(
+            new_ce = {name: ce[name].at[phys, :, off].set(
                           packed[name][0].astype(ce[name].dtype))
                       for name in packed}
             a = paged_append_attention(q[0], new_ce["k"], new_ce["v"], pt,

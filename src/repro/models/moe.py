@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import MoEConfig
 from repro.models.pdefs import ParamDef
@@ -201,13 +200,13 @@ def _moe_ffn_ep(params, x, m: MoEConfig, mesh, *, group_size: int = 4096,
         return out, aux
 
     other = tuple(a for a in mesh.axis_names if a not in (batch_axes or ()))
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(gspec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(gspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(xg, params["router"], params["wi_gate"], params["wi_up"], params["wo"])
     out = out.reshape(B, S, D)
     if m.n_shared_experts:

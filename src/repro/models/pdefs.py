@@ -21,6 +21,7 @@ Logical axis names used across the codebase:
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Tuple
 
@@ -88,7 +89,9 @@ def init_from_defs(defs, key):
     """Initialize real parameters. Keys are derived per-path (stable)."""
     def f(path, d):
         pstr = jax.tree_util.keystr(path)
-        sub = jax.random.fold_in(key, hash(pstr) % (2**31))
+        # crc32, not hash(): str hashes are salted per process, and weights
+        # made from a seed must be the same in every process
+        sub = jax.random.fold_in(key, zlib.crc32(pstr.encode()) % (2**31))
         return _init_leaf(sub, d)
     return _tmap_with_path(f, defs)
 

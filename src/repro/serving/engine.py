@@ -5,7 +5,7 @@ deployment — behind the cloud tier. Requests stream through a fixed pool of
 ``max_batch`` slots; the KV-cache behind those slots comes in two layouts:
 
 * ``paged`` (default where the model supports it) — one global page arena
-  per layer, ``[num_pages + 1, page_size, KV, hd]``, plus a host-side
+  per layer, ``[num_pages + 1, KV, page_size, hd]``, plus a host-side
   per-slot page table ``[max_batch, max_seq // page_size]`` of physical page
   ids. A slot reserves only ``ceil((prompt + decode_budget) / page_size)``
   pages at admission, so short requests no longer strand a worst-case

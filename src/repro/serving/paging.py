@@ -1,7 +1,7 @@
 """Host-side page allocator + prefix index for the paged KV-cache.
 
-The device holds one page arena per layer (``[num_pages + 1, page_size,
-...]``); this module owns the *ids*. Physical page 0 is reserved as the
+The device holds one page arena per layer (``[num_pages + 1, KV,
+page_size, hd]``); this module owns the *ids*. Physical page 0 is reserved as the
 trash page: page-table entries beyond a slot's allocation point at it, so
 fixed-shape scatters can always write a full table row and fixed-shape
 gathers can always read one — writes land in trash, reads are masked by the

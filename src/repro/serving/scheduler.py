@@ -177,6 +177,8 @@ class Completion:
     #                              final admission (an upper bound for
     #                              preempted-then-resumed requests, whose
     #                              true first token came even earlier)
+    token_ids: List[int] = field(default_factory=list)   # generated ids
+    #                              (text keeps only the byte-range ones)
 
 
 @dataclass
@@ -439,7 +441,8 @@ class TierScheduler:
                         preemptions=item.preemptions,
                         hedged=item.is_hedge,
                         ttft_s=item.queue_wait_s + item.resident_s
-                        + ec.ttft_s))
+                        + ec.ttft_s,
+                        token_ids=ids))
                 eng.dispatch()
                 # residents on an engine that just stepped made progress
                 for key, it in self._inflight.items():

@@ -11,13 +11,18 @@ from repro.kernels.decode_attention.kernel import (
     paged_decode_attention_pallas,
 )
 from repro.kernels.decode_attention.ref import (
-    decode_attention_ref, paged_append_attention_ref,
+    decode_attention_ref, gather_pages, paged_append_attention_ref,
     paged_decode_attention_ref,
 )
 from repro.kernels.retrieval_topk.kernel import retrieval_topk_pallas
 from repro.kernels.retrieval_topk.ref import retrieval_topk_ref
 from repro.kernels.rbf.kernel import rbf_matrix_pallas
 from repro.kernels.rbf.ref import rbf_matrix_ref
+
+
+def _kv_major(cache):
+    """[B, S, KV, hd] (the oracle's layout) -> [B, KV, S, hd] (the kernel's)."""
+    return cache.transpose(0, 2, 1, 3)
 
 
 @pytest.mark.parametrize("B,H,KV,hd,S,block_s", [
@@ -34,7 +39,8 @@ def test_decode_attention_sweep(B, H, KV, hd, S, block_s, dtype):
     k = jax.random.normal(k2, (B, S, KV, hd), dtype)
     v = jax.random.normal(k3, (B, S, KV, hd), dtype)
     lengths = jax.random.randint(k4, (B,), 1, S + 1)
-    out = decode_attention_pallas(q, k, v, lengths, block_s=block_s)
+    out = decode_attention_pallas(q, _kv_major(k), _kv_major(v), lengths,
+                                  block_s=block_s)
     ref = decode_attention_ref(q, k, v, lengths)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -49,10 +55,10 @@ def test_decode_attention_length_mask_strict():
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KV, hd))
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KV, hd))
     lengths = jnp.array([40])
-    out1 = decode_attention_pallas(q, k, v, lengths)
+    out1 = decode_attention_pallas(q, _kv_major(k), _kv_major(v), lengths)
     k2 = k.at[:, 40:].set(999.0)
     v2 = v.at[:, 40:].set(-999.0)
-    out2 = decode_attention_pallas(q, k2, v2, lengths)
+    out2 = decode_attention_pallas(q, _kv_major(k2), _kv_major(v2), lengths)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
 
 
@@ -71,7 +77,8 @@ def test_decode_attention_block_clamp_regression(S, block_s):
     k = jax.random.normal(k2, (B, S, KV, hd))
     v = jax.random.normal(k3, (B, S, KV, hd))
     lengths = jnp.array([S, max(1, S - 3)])
-    out = decode_attention_pallas(q, k, v, lengths, block_s=block_s)
+    out = decode_attention_pallas(q, _kv_major(k), _kv_major(v), lengths,
+                                  block_s=block_s)
     ref = decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
@@ -93,7 +100,8 @@ def test_decode_attention_length_edges(length):
     lengths = {"zero": jnp.array([0, 0]),
                "full": jnp.array([S, S]),
                "ragged": jnp.array([bs - 5, S - 7])}[length]
-    out = decode_attention_pallas(q, k, v, lengths, block_s=bs)
+    out = decode_attention_pallas(q, _kv_major(k), _kv_major(v), lengths,
+                                  block_s=bs)
     ref = decode_attention_ref(q, k, v, lengths)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -107,11 +115,11 @@ def test_decode_attention_length_edges(length):
 # ---------------------------------------------------------------------------
 
 def _ragged_paged_cache(B, P, ps, KV, hd, pages_per_row, seed=0):
-    """Random arenas + page tables with distinct physical pages per row
-    (scattered, unordered) and trash-page-0 padding."""
+    """Random [P, KV, ps, hd] arenas + page tables with distinct physical
+    pages per row (scattered, unordered) and trash-page-0 padding."""
     rng = np.random.default_rng(seed)
-    k_arena = jnp.asarray(rng.normal(size=(P, ps, KV, hd)).astype(np.float32))
-    v_arena = jnp.asarray(rng.normal(size=(P, ps, KV, hd)).astype(np.float32))
+    k_arena = jnp.asarray(rng.normal(size=(P, KV, ps, hd)).astype(np.float32))
+    v_arena = jnp.asarray(rng.normal(size=(P, KV, ps, hd)).astype(np.float32))
     n_pages = max(pages_per_row)
     pt = np.zeros((B, n_pages), np.int32)
     perm = rng.permutation(np.arange(1, P))
@@ -150,8 +158,11 @@ def test_paged_matches_contiguous_oracle_ragged_tables():
     q = jax.random.normal(jax.random.PRNGKey(2), (B, H, hd))
     lengths = jnp.array([7 * ps, 5 * ps - 3, ps + 1, 0], jnp.int32)
     out = paged_decode_attention_pallas(q, k_arena, v_arena, pt, lengths)
-    k_c = k_arena[pt].reshape(B, n_pages * ps, KV, hd)
-    v_c = v_arena[pt].reshape(B, n_pages * ps, KV, hd)
+    # reassemble by hand (not through gather_pages, which the paged ref uses)
+    k_c = k_arena[pt].transpose(0, 1, 3, 2, 4).reshape(B, n_pages * ps, KV, hd)
+    v_c = v_arena[pt].transpose(0, 1, 3, 2, 4).reshape(B, n_pages * ps, KV, hd)
+    np.testing.assert_array_equal(np.asarray(gather_pages(k_arena, pt)),
+                                  np.asarray(k_c))
     ref = decode_attention_ref(q, k_c, v_c, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
@@ -170,8 +181,8 @@ def test_paged_trash_page_contents_never_leak():
     k2 = k_arena.at[0].set(999.0)                 # poison trash page
     v2 = v_arena.at[0].set(-999.0)
     # poison the tail of each row's last valid page too
-    k2 = k2.at[pt[0, 3], ps - 9:].set(777.0)
-    v2 = v2.at[pt[0, 3], ps - 9:].set(-777.0)
+    k2 = k2.at[pt[0, 3], :, ps - 9:].set(777.0)
+    v2 = v2.at[pt[0, 3], :, ps - 9:].set(-777.0)
     out2 = paged_decode_attention_pallas(q, k2, v2, pt, lengths)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
 
@@ -182,8 +193,8 @@ def test_paged_trash_page_contents_never_leak():
 
 def _append_case(P, ps, KV, hd, n_pages, seed=0):
     rng = np.random.default_rng(seed)
-    k_arena = jnp.asarray(rng.normal(size=(P, ps, KV, hd)).astype(np.float32))
-    v_arena = jnp.asarray(rng.normal(size=(P, ps, KV, hd)).astype(np.float32))
+    k_arena = jnp.asarray(rng.normal(size=(P, KV, ps, hd)).astype(np.float32))
+    v_arena = jnp.asarray(rng.normal(size=(P, KV, ps, hd)).astype(np.float32))
     pt = np.zeros(n_pages, np.int32)
     perm = rng.permutation(np.arange(1, P))
     pt[:] = perm[:n_pages]
@@ -262,8 +273,8 @@ def test_paged_append_causal_and_stale_page_masking():
     total = prefix + suffix
     k2 = k_arena.at[0].set(999.0)
     v2 = v_arena.at[0].set(-999.0)
-    k2 = k2.at[pt[1], total - ps:].set(777.0)
-    v2 = v2.at[pt[1], total - ps:].set(-777.0)
+    k2 = k2.at[pt[1], :, total - ps:].set(777.0)
+    v2 = v2.at[pt[1], :, total - ps:].set(-777.0)
     k2 = k2.at[pt[2]].set(555.0)
     v2 = v2.at[pt[2]].set(-555.0)
     out2 = paged_append_attention_pallas(q, k2, v2, pt, lens)

@@ -110,6 +110,8 @@ def test_completion_accounting(engine, sched):
         assert c.prompt_tokens == len(engine.tok.encode(r.prompt))
         assert 0 < c.new_tokens <= r.max_new_tokens
         assert len(engine.tok.encode(c.text, bos=False)) == c.new_tokens
+        assert len(c.token_ids) == c.new_tokens
+        assert engine.tok.decode(c.token_ids) == c.text
 
 
 # ---------------------------------------------------------------------------

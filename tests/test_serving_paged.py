@@ -320,6 +320,33 @@ def test_contiguous_layout_still_available():
     texts, _ = eng.generate([Request("hello", max_new_tokens=3)])
     assert len(texts) == 1
 
+def test_kv_head_major_arena_at_two_kv_heads():
+    """The arena is [layers, pages, KV, page_size, hd] and the page axis is
+    found from its names, so the CoW page copy and the token scatters stay
+    right with KV > 1 (the reduced configs all have KV == 1): paged greedy
+    output, prefix hits and CoW tails included, equals contiguous lanes."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.serving.engine import ServingEngine
+    cfg = dataclasses.replace(get_config("qwen2-0.5b", reduced=True),
+                              n_heads=4, n_kv_heads=2)
+    paged = ServingEngine(cfg, max_seq=96, max_batch=3, seed=0)
+    contiguous = ServingEngine(cfg, max_seq=96, max_batch=3, seed=0,
+                               kv_layout="contiguous")
+    hd = cfg.resolved_head_dim
+    for leaf in (paged._cache["blocks"]["k"], paged._cache["blocks"]["v"]):
+        assert leaf.shape == (cfg.n_layers, paged.num_pages + 1, 2,
+                              paged.page_size, hd)
+    assert paged._page_ax["blocks"]["k"] == 1
+    ctx = "shared retrieved context: the Eiffel Tower is in Paris. "
+    reqs = REQS + [Request(ctx + q, max_new_tokens=5)
+                   for q in ("who?", "where?")]
+    out_p, st = paged.generate(reqs)
+    out_c, _ = contiguous.generate(reqs)
+    assert out_p == out_c
+    assert st.prefix_hits >= 1 and paged.trace_counts["copy"] == 1
+
+
 def test_paged_rejected_for_unpageable_model():
     from repro.configs import get_config
     from repro.serving.engine import ServingEngine
