@@ -635,13 +635,13 @@ class EACOCluster:
                 return self.faults.stalled(t, i, _now, len(pools[t]))
 
         flat = [(t, e) for t, pool in self.sched.pools.items() for e in pool]
-        pre = [(e.prefill_tokens, e.decode_rounds, e.prefill_s + e.decode_s)
-               for _, e in flat]
+        pre = [(e.prefill_tokens, e.decode_rounds,
+                e.prefill_wall_s + e.decode_wall_s) for _, e in flat]
         comps = self.sched.pump(now=now, stalled=stalled)
         dt = 0.0
         for (tier_name, e), (p0, r0, w0) in zip(flat, pre):
             if self.cfg.engine_time == "wall":
-                dt_e = (e.prefill_s + e.decode_s) - w0
+                dt_e = (e.prefill_wall_s + e.decode_wall_s) - w0
             else:
                 spec = (self.edge_tier if tier_name == "edge"
                         else self.cloud_tier)

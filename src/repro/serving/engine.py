@@ -169,8 +169,7 @@ returning seconds; default ``time.perf_counter``) — a simulator injecting a
 compose with its queue waits instead of mixing wall and event time. The
 compute timers are explicitly wall-clock and NAMED so:
 ``prefill_wall_s``/``decode_wall_s`` measure real jit compute for
-``engine_time="wall"`` (``prefill_s``/``decode_s`` remain as read-only
-aliases), while the *logical* counters — ``prefill_tokens``,
+``engine_time="wall"``, while the *logical* counters — ``prefill_tokens``,
 ``decode_rounds``, ``prefill_chunks``, ``mixed_steps`` — are pure
 functions of the request stream, so DST replays that compare engine
 progress stay byte-identical regardless of host speed.
@@ -197,6 +196,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.tracing import span
 from repro.data.tokenizer import ByteTokenizer
 from repro.models.api import Model, build_model
 from repro.models.pdefs import is_pdef
@@ -293,6 +293,7 @@ class _Slot:
     enc: List[int] = field(default_factory=list)   # encoded prompt
     # ---- budget-mode partial-prefill state ----------------------------
     prefill_done: int = 0        # prompt tokens already in the arena
+    prefix_tokens: int = 0       # of which the prefix cache served
     page_row: Optional[np.ndarray] = None   # full page-table row, applied
     #                              to the decode table at prefill finish
     first_token_at: Optional[float] = None  # engine clock at first sample
@@ -557,16 +558,6 @@ class ServingEngine:
         return self.trace_counts["decode"]
 
     @property
-    def prefill_s(self) -> float:
-        """Read-only alias of :attr:`prefill_wall_s` (historical name)."""
-        return self.prefill_wall_s
-
-    @property
-    def decode_s(self) -> float:
-        """Read-only alias of :attr:`decode_wall_s` (historical name)."""
-        return self.decode_wall_s
-
-    @property
     def prefilling_slots(self) -> int:
         """Residents still mid-prefill (budget mode; no first token yet)."""
         return sum(1 for s in self._slots
@@ -713,6 +704,10 @@ class ServingEngine:
         into freshly allocated pages. Returns the engine-local request id
         used in :class:`EngineCompletion`. Callers gate on
         :meth:`can_admit`."""
+        with span("engine.admit") as sp:
+            return self._admit(request, sp)
+
+    def _admit(self, request: Request, sp) -> int:
         if self.dead:
             raise EngineError("admit: engine crashed; restart() first")
         slot = next((i for i, s in enumerate(self._slots) if s is None), None)
@@ -776,8 +771,10 @@ class ServingEngine:
                 self._slots[slot] = _Slot(
                     rid, request, budget, L, None,
                     admitted_at=self._clock(), page_ids=page_ids, enc=enc,
-                    prefill_done=prefix_len, page_row=row)
+                    prefill_done=prefix_len, prefix_tokens=prefix_len,
+                    page_row=row)
                 self.peak_active = max(self.peak_active, self.active_slots)
+                sp.set(rid=rid, prompt_tokens=L, prefix_tokens=prefix_len)
                 return rid
             suffix = enc[prefix_len:]
             pad_len = self._pad_bucket(len(suffix))
@@ -798,6 +795,7 @@ class ServingEngine:
                 self.prefix_tokens_shared += prefix_len
         else:
             page_ids = None
+            prefix_len = 0
             pad_len = self._pad_bucket(L)
             tokens, lengths = self.tok.pad_batch([enc], pad_len)
             logits, lane = self._prefill(self.params, jnp.asarray(tokens),
@@ -820,6 +818,7 @@ class ServingEngine:
         self._positions[slot] = L
         self._temps[slot] = request.temperature
         self.peak_active = max(self.peak_active, self.active_slots)
+        sp.set(rid=rid, prompt_tokens=L, prefix_tokens=prefix_len)
         return rid
 
     def step(self) -> List[EngineCompletion]:
@@ -841,25 +840,27 @@ class ServingEngine:
         ``pending is None``) have nothing to emit and are skipped."""
         if self.dead:
             raise EngineError("step: engine crashed; restart() first")
-        done: List[EngineCompletion] = []
-        now = self._clock()
-        for i, s in enumerate(self._slots):
-            if s is None or s.pending is None:
-                continue
-            finished = (s.pending == self.tok.eos_id
-                        or len(s.out_ids) >= s.budget)
-            if not finished:
-                s.out_ids.append(s.pending)
-                finished = len(s.out_ids) >= s.budget
-            if finished:
-                ft = (s.first_token_at if s.first_token_at is not None
-                      else s.admitted_at)
-                done.append(EngineCompletion(
-                    s.req_id, s.request, self.tok.decode(s.out_ids),
-                    s.out_ids, s.prompt_tokens, len(s.out_ids),
-                    time_in_engine_s=max(now - s.admitted_at, 0.0),
-                    ttft_s=max(ft - s.admitted_at, 0.0)))
-                self._free(i)
+        with span("engine.harvest") as sp:
+            done: List[EngineCompletion] = []
+            now = self._clock()
+            for i, s in enumerate(self._slots):
+                if s is None or s.pending is None:
+                    continue
+                finished = (s.pending == self.tok.eos_id
+                            or len(s.out_ids) >= s.budget)
+                if not finished:
+                    s.out_ids.append(s.pending)
+                    finished = len(s.out_ids) >= s.budget
+                if finished:
+                    ft = (s.first_token_at if s.first_token_at is not None
+                          else s.admitted_at)
+                    done.append(EngineCompletion(
+                        s.req_id, s.request, self.tok.decode(s.out_ids),
+                        s.out_ids, s.prompt_tokens, len(s.out_ids),
+                        time_in_engine_s=max(now - s.admitted_at, 0.0),
+                        ttft_s=max(ft - s.admitted_at, 0.0)))
+                    self._free(i)
+            sp.set(finished=len(done))
         return done
 
     def _pick_chunk(self, n_decode: int):
@@ -901,58 +902,80 @@ class ServingEngine:
         if self._outstanding is not None:
             raise EngineError(
                 "dispatch: a step is already in flight; collect() first")
-        dec = [(i, s.req_id) for i, s in enumerate(self._slots)
-               if s is not None and s.pending is not None]
-        chunk = self._pick_chunk(len(dec)) if self.budget_mode else None
-        if not dec and chunk is None:
-            return
-        t0 = time.perf_counter()
-        out = {"t0": t0, "dec": dec, "dec_tokens": None, "chunk": None}
-        if self.budget_mode:
-            self.budget_steps += 1
-            self.budget_tokens_used += len(dec) + (chunk[2] if chunk else 0)
-        dec_logits = None
-        if chunk is not None:
-            ci, cs, clen = chunk
-            lo = cs.prefill_done
-            ctoks, _ = self.tok.pad_batch([cs.enc[lo:lo + clen]],
-                                          self._chunk_pad)
-            finishing = lo + clen >= cs.prompt_tokens
-            if dec:
-                dec_logits, chunk_logits, self._cache = self._fused(
-                    self.params, self._cache,
-                    jnp.asarray(self._tokens)[:, None],
-                    jnp.asarray(self._positions),
-                    jnp.asarray(self._page_tables),
-                    jnp.asarray(ctoks), jnp.int32(clen), jnp.int32(lo),
-                    jnp.asarray(cs.page_row))
-            else:
-                chunk_logits, self._cache = self._prefill_paged(
-                    self.params, self._cache, jnp.asarray(ctoks),
-                    jnp.int32(clen), jnp.int32(lo),
-                    jnp.asarray(cs.page_row))
-            ctok = None
-            if finishing:     # only the FINAL chunk's logits are the first-
-                self._key, sub = jax.random.split(self._key)  # token logits
-                ctok = self._sample(
-                    chunk_logits,
-                    jnp.asarray([cs.request.temperature], jnp.float32), sub)
-            out["chunk"] = (ci, cs.req_id, clen, finishing, ctok)
-        elif dec:
-            args = (self.params, self._cache,
-                    jnp.asarray(self._tokens)[:, None],
-                    jnp.asarray(self._positions))
-            if self.kv_layout == "paged":
-                args += (jnp.asarray(self._page_tables),)
-            dec_logits, self._cache = self._decode(*args)
-        if dec:
-            self.decode_rounds += 1
-            if chunk is not None:
-                self.mixed_steps += 1
-            self._key, sub = jax.random.split(self._key)
-            out["dec_tokens"] = self._sample(dec_logits,
-                                             jnp.asarray(self._temps), sub)
-        self._outstanding = out
+        with span("engine.dispatch") as sp:
+            with span("engine.prepare"):
+                dec = [(i, s.req_id) for i, s in enumerate(self._slots)
+                       if s is not None and s.pending is not None]
+                chunk = (self._pick_chunk(len(dec)) if self.budget_mode
+                         else None)
+                if not dec and chunk is None:
+                    return
+                t0 = time.perf_counter()
+                step = (self.budget_steps if self.budget_mode
+                        else self.decode_rounds)
+                out = {"t0": t0, "step": step, "dec": dec, "dec_tokens": None,
+                       "chunk": None}
+                if self.budget_mode:
+                    self.budget_steps += 1
+                    self.budget_tokens_used += len(dec) + (
+                        chunk[2] if chunk else 0)
+                if chunk is not None:
+                    ci, cs, clen = chunk
+                    lo = cs.prefill_done
+                    ctoks, _ = self.tok.pad_batch([cs.enc[lo:lo + clen]],
+                                                  self._chunk_pad)
+                    finishing = lo + clen >= cs.prompt_tokens
+                    sp.set(step=step, kind="fused" if dec else "prefill",
+                           decode_rows=len(dec), chunk_rid=cs.req_id,
+                           chunk_tokens=clen,
+                           first_chunk=int(lo == cs.prefix_tokens),
+                           final_chunk=int(finishing))
+                else:
+                    sp.set(step=step, kind="decode", decode_rows=len(dec))
+                # the host-to-device copies in a span of their own, so that
+                # device idle inside prepare tells them from the host's work
+                with span("engine.prepare.upload"):
+                    dec_args = ()
+                    if dec:
+                        dec_args = (jnp.asarray(self._tokens)[:, None],
+                                    jnp.asarray(self._positions))
+                        if self.kv_layout == "paged":
+                            dec_args += (jnp.asarray(self._page_tables),)
+                    if chunk is not None:
+                        chunk_args = (jnp.asarray(ctoks), jnp.int32(clen),
+                                      jnp.int32(lo),
+                                      jnp.asarray(cs.page_row))
+            with span("engine.launch") as lp:
+                traces = sum(self.trace_counts.values())
+                if chunk is None:
+                    dec_logits, self._cache = self._decode(
+                        self.params, self._cache, *dec_args)
+                elif dec:
+                    dec_logits, chunk_logits, self._cache = self._fused(
+                        self.params, self._cache, *dec_args, *chunk_args)
+                else:
+                    chunk_logits, self._cache = self._prefill_paged(
+                        self.params, self._cache, *chunk_args)
+                lp.set(compiled=sum(self.trace_counts.values()) - traces)
+            with span("engine.sample"):
+                if chunk is not None:
+                    ctok = None
+                    if finishing:
+                        # only the FINAL chunk's logits are first-token ones
+                        self._key, sub = jax.random.split(self._key)
+                        ctok = self._sample(
+                            chunk_logits,
+                            jnp.asarray([cs.request.temperature], jnp.float32),
+                            sub)
+                    out["chunk"] = (ci, cs.req_id, clen, finishing, ctok)
+                if dec:
+                    self.decode_rounds += 1
+                    if chunk is not None:
+                        self.mixed_steps += 1
+                    self._key, sub = jax.random.split(self._key)
+                    out["dec_tokens"] = self._sample(
+                        dec_logits, jnp.asarray(self._temps), sub)
+            self._outstanding = out
 
     def collect(self) -> None:
         """Block on the in-flight step (if any) and apply its results
@@ -966,40 +989,44 @@ class ServingEngine:
         if self._outstanding is None:
             return
         out, self._outstanding = self._outstanding, None
-        nxt = None
-        if out["dec_tokens"] is not None:
-            nxt = np.asarray(jax.block_until_ready(out["dec_tokens"]))
-        ch = out["chunk"]
-        ctok_val = None
-        if ch is not None and ch[4] is not None:
-            ctok_val = int(jax.block_until_ready(ch[4])[0])
-        span = time.perf_counter() - out["t0"]
-        if out["dec"]:
-            self.decode_wall_s += span
-        else:
-            self.prefill_wall_s += span
-        for i, rid in out["dec"]:
-            s = self._slots[i]
-            if s is None or s.req_id != rid or s.pending is None:
-                continue      # retired/preempted while in flight
-            s.pending = int(nxt[i])
-            self._tokens[i] = s.pending
-            self._positions[i] += 1
-        if ch is not None:
-            ci, rid, clen, finishing, _ = ch
-            s = self._slots[ci]
-            if s is not None and s.req_id == rid and s.pending is None:
-                s.prefill_done += clen
-                self.prefill_tokens += clen
-                self.prefill_chunks += 1
-                if finishing:
-                    s.pending = ctok_val
-                    self._tokens[ci] = ctok_val
-                    self._positions[ci] = s.prompt_tokens
-                    self._page_tables[ci] = s.page_row
-                    s.first_token_at = self._clock()
-                    if self._prefix is not None:
-                        self._prefix.insert(s.enc, s.page_row)
+        with span("engine.collect", step=out["step"]):
+            with span("engine.collect.wait"):
+                nxt = None
+                if out["dec_tokens"] is not None:
+                    nxt = np.asarray(jax.block_until_ready(out["dec_tokens"]))
+                ch = out["chunk"]
+                ctok_val = None
+                if ch is not None and ch[4] is not None:
+                    ctok_val = int(jax.block_until_ready(ch[4])[0])
+            with span("engine.collect.apply") as sp:
+                wall = time.perf_counter() - out["t0"]
+                if out["dec"]:
+                    self.decode_wall_s += wall
+                else:
+                    self.prefill_wall_s += wall
+                for i, rid in out["dec"]:
+                    s = self._slots[i]
+                    if s is None or s.req_id != rid or s.pending is None:
+                        continue      # retired/preempted while in flight
+                    s.pending = int(nxt[i])
+                    self._tokens[i] = s.pending
+                    self._positions[i] += 1
+                if ch is not None:
+                    ci, rid, clen, finishing, _ = ch
+                    s = self._slots[ci]
+                    if s is not None and s.req_id == rid and s.pending is None:
+                        s.prefill_done += clen
+                        self.prefill_tokens += clen
+                        self.prefill_chunks += 1
+                        if finishing:
+                            s.pending = ctok_val
+                            self._tokens[ci] = ctok_val
+                            self._positions[ci] = s.prompt_tokens
+                            self._page_tables[ci] = s.page_row
+                            s.first_token_at = self._clock()
+                            if self._prefix is not None:
+                                self._prefix.insert(s.enc, s.page_row)
+                            sp.set(first_token_rid=rid)
 
     def _free(self, slot: int) -> None:
         s = self._slots[slot]

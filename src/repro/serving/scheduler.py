@@ -113,6 +113,7 @@ from typing import (
     Callable, Dict, List, Optional, Sequence, Tuple, Union,
 )
 
+from repro.core.tracing import span
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.health import CircuitBreaker
 
@@ -167,7 +168,6 @@ class Completion:
     prompt_tokens: int = 0
     new_tokens: int = 0
     engine_index: int = 0        # which pool member finished it
-    engine_wall_s: float = 0.0   # engine-measured wall time (last residency)
     slo: str = "batch"
     preemptions: int = 0         # times this request was preempted
     hedged: bool = False         # served by the backup (hedge) submission
@@ -366,6 +366,13 @@ class TierScheduler:
         progress, and — with ``request_timeout_s`` — eventually time out
         and free their slots. Dead engines (crashed, not yet restarted) are
         likewise skipped, after their lost residents are reaped."""
+        with span("sched.pump", queued=self.pending(),
+                  resident=self.in_flight()):
+            return self._pump(now, stalled)
+
+    def _pump(self, now: Optional[float],
+              stalled: Optional[Callable[[str, int], bool]]
+              ) -> List[Completion]:
         t_round = self.clock() if now is None else now
         out: List[Completion] = []
         for tier, pool in self.pools.items():
@@ -436,7 +443,6 @@ class TierScheduler:
                                        else ec.prompt_tokens),
                         new_tokens=len(ids),
                         engine_index=eng_i,
-                        engine_wall_s=ec.time_in_engine_s,
                         slo=primary.request.slo,
                         preemptions=item.preemptions,
                         hedged=item.is_hedge,

@@ -135,7 +135,6 @@ def test_queue_wait_exact_under_injected_clock(engine):
     c = done[0]
     assert c.queue_wait_s == 3.5                   # exact, not approximate
     assert c.time_in_engine_s == 0.25 * (rounds - 1)
-    assert c.engine_wall_s > 0.0                   # real compute happened
 
 
 def test_pump_now_overrides_per_round(engine):
