@@ -16,7 +16,9 @@ One online-softmax accumulation over sequence blocks serves three callers:
 * paged append — the multi-token sibling of paged decode, used by
   prefix-cached suffix prefill: q is a ``[block_q, H, hd]`` chunk of new
   tokens at absolute positions ``prefix_len + i``, and the grid's seq axis
-  chases the (scalar-prefetched) page table over *prefix + suffix* pages.
+  walks KV blocks of many pages, each page chasing the (scalar-prefetched)
+  page table over *prefix + suffix* pages, up to the tile's causal
+  frontier.
   The causal mask lives entirely inside the q tile's position arithmetic:
   key position <= query position admits every shared-prefix key and the
   already-written part of the suffix, exactly like a causal prefill over
@@ -50,6 +52,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -249,14 +252,88 @@ def paged_decode_attention_pallas(q, k_arena, v_arena, page_table, lengths, *,
     return out.reshape(B, H, hd)
 
 
-def _paged_append_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                              m_ref, l_ref, acc_ref, *, page_size: int,
+# KV tokens one append grid step walks: 32 pages at page size 16, 4 at 128
+APPEND_BLOCK_TOKENS = 512
+
+
+def _append_tiling(S: int, n_pages: int, page_size: int, block_q: int):
+    """(block_q, pages_per_block, n_blocks) of the append kernel's grid.
+
+    ``block_q`` is clamped to divide S at a multiple of 8; a KV block is
+    ``APPEND_BLOCK_TOKENS`` worth of pages, at least one and at most the
+    whole page table."""
+    block_q = min(block_q, S)
+    while S % block_q:
+        block_q -= 8
+    ppb = max(1, min(n_pages, APPEND_BLOCK_TOKENS // page_size))
+    return block_q, ppb, -(-n_pages // ppb)
+
+
+def _tile_kv_end(q_first, total, block_q: int, xp):
+    """One past the last key any valid query of a tile can attend: the tile
+    starts at absolute position ``q_first``, its last valid query sits at
+    ``min(q_first + block_q, total) - 1``; a tile wholly at or past
+    ``total`` (chunk padding) attends nothing. ``xp`` is ``jnp`` for the
+    kernel and ``numpy`` for the host's count, so both walk by one rule."""
+    return xp.where(q_first < total, xp.minimum(q_first + block_q, total), 0)
+
+
+def append_walk(prefix_len: int, total_len: int, chunk: int, n_pages: int,
+                page_size: int, *, block_q: int = 128):
+    """(walked, grid): the (query tile, KV block) pairs of one KV head that
+    :func:`paged_append_attention_pallas` computes for a ``chunk``-token
+    (padded) suffix at ``prefix_len`` with ``total_len`` valid positions,
+    against the pairs in its static grid. Host arithmetic only."""
+    block_q, ppb, n_blocks = _append_tiling(chunk, n_pages, page_size,
+                                            block_q)
+    bt = ppb * page_size
+    n_qc = chunk // block_q
+    walked = sum(-(-int(_tile_kv_end(prefix_len + c * block_q, total_len,
+                                     block_q, np)) // bt)
+                 for c in range(n_qc))
+    return walked, n_qc * n_blocks
+
+
+def _append_walk_steps(prefix, total, n_qc: int, n_blocks: int,
+                       block_q: int, pages_per_block: int, page_size: int):
+    """What each grid step (c, j) of the append kernel holds: the query
+    tile, ``[n_qc]``, and the KV block's first page-table index,
+    ``[n_qc * n_blocks]``. A live step holds its own; a step past its
+    tile's frontier holds the tile's last live block; a tile of padding
+    holds the last live tile and its last block. A skipped step thus
+    repeats the step before it, and the pipeline, which copies only blocks
+    that change, issues no DMA for it."""
+    bt = pages_per_block * page_size
+    last = jnp.maximum((total - 1 - prefix) // block_q, 0)
+    tile = jnp.minimum(jnp.arange(n_qc, dtype=jnp.int32), last)
+    n_live = (_tile_kv_end(prefix + tile * block_q, total, block_q, jnp)
+              + bt - 1) // bt
+    blk = jnp.minimum(jnp.arange(n_blocks, dtype=jnp.int32)[None, :],
+                      jnp.maximum(n_live - 1, 0)[:, None])
+    return tile, (blk * pages_per_block).reshape(-1)
+
+
+def _paged_append_attn_kernel(pt_ref, len_ref, tile_ref, base_ref, q_ref,
+                              *refs, pages_per_block: int, page_size: int,
                               block_q: int, group: int, scale: float):
-    """Paged append (chunked suffix prefill). Grid: (n_q_chunks, KV,
-    n_pages) with the page axis innermost so the accumulators carry across
-    the whole logical sequence; the k/v BlockSpecs already chased the
-    scalar-prefetched page table, so the body only needs position
-    arithmetic.
+    """Paged append (chunked suffix prefill). Grid: (KV, n_q_chunks,
+    n_blocks) with the KV-block axis innermost so the accumulators carry
+    across the whole logical sequence. A KV block is ``pages_per_block``
+    logical pages: k and v each arrive as that many ``[page_size, hd]``
+    page operands, whose BlockSpecs chased the scalar-prefetched page
+    table (the pipeline loads block i + 1 while block i computes), and are
+    stacked here into one ``[pages_per_block * page_size, hd]`` block.
+    ``tile_ref``/``base_ref`` (SMEM) are :func:`_append_walk_steps`: only
+    the index maps read them. Pages come through BlockSpecs rather than
+    DMAs issued from an HBM (``pl.ANY``) arena because the TPU compiler
+    pads a 64-wide minor dim to 128 lanes in HBM and refuses a DMA slice
+    of it, which rules out qwen2-0.5b's head dim of 64.
+
+    The walk stops at the tile's causal frontier: blocks whose first key
+    lies past the tile's last valid query are neither copied nor
+    computed, and a tile of chunk padding (first row at or past
+    ``total_len``) copies nothing and writes its zeros. The grid stays
+    static; a skipped step costs only the pipeline's bookkeeping.
 
     q_ref: [block_q * G, hd] — row r is query token ``r // G`` of this
     chunk, group member ``r % G``; its absolute position is ``prefix_len +
@@ -266,37 +343,43 @@ def _paged_append_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     output is the defined zero and the engine never reads them).
     len_ref: [2] (SMEM) = (prefix_len, total_len = prefix_len + suffix_len).
     """
-    i = pl.program_id(2)
-    n_i = pl.num_programs(2)
+    k_refs = refs[:pages_per_block]
+    v_refs = refs[pages_per_block:2 * pages_per_block]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages_per_block:]
+    c, j = pl.program_id(1), pl.program_id(2)
+    bt = pages_per_block * page_size
+    prefix = len_ref[0]
+    total = len_ref[1]
+    kv_end = _tile_kv_end(prefix + c * block_q, total, block_q, jnp)
 
-    @pl.when(i == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)                    # [block_q*G, hd]
-    k = k_ref[...].astype(jnp.float32)                    # [page_size, hd]
-    v = v_ref[...].astype(jnp.float32)
+    @pl.when(j * bt < kv_end)
+    def _walk():
+        q = q_ref[...]                                    # [block_q*G, hd]
+        k = jnp.concatenate([r[...] for r in k_refs], axis=0)   # [bt, hd]
+        if q.dtype != k.dtype:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        v = jnp.concatenate([r[...] for r in v_refs], axis=0)
+        block_start = j * bt
+        # zero value rows past the tile's frontier (later suffix tokens,
+        # stale pages, the trash page, table padding) before they can meet
+        # the accumulators: 0 * NaN would poison the p @ v product
+        vpos = block_start + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        v = jnp.where(vpos < kv_end, v.astype(jnp.float32), 0.0)
+        qpos = (prefix + c * block_q
+                + jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0)
+                // group)
+        kpos = block_start + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        valid = (kpos <= qpos) & (qpos < total)           # causal + q padding
+        _softmax_accumulate(q, k, v, valid, m_ref, l_ref, acc_ref,
+                            scale=scale)
 
-    prefix = len_ref[0]
-    total = len_ref[1]
-    page_start = i * page_size
-    # zero value rows at positions never written (stale pages / trash /
-    # interpret-mode padding) before they can meet the accumulators
-    vpos = page_start + jax.lax.broadcasted_iota(
-        jnp.int32, (page_size, 1), 0)
-    v = jnp.where(vpos < total, v, 0.0)
-
-    rows = q.shape[0]
-    qpos = (prefix + pl.program_id(0) * block_q
-            + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group)
-    kpos = page_start + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, page_size), 1)
-    valid = (kpos <= qpos) & (qpos < total)               # causal + q padding
-    _softmax_accumulate(q, k, v, valid, m_ref, l_ref, acc_ref, scale=scale)
-
-    @pl.when(i == n_i - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _done():
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                       ).astype(o_ref.dtype)
@@ -314,13 +397,15 @@ def paged_append_attention_pallas(q, k_arena, v_arena, page_table, lens, *,
     (prefix_len, total_len), scalar-prefetched beside the page table.
     Returns [S, H, hd].
 
-    The grid is (S / block_q, KV, n_pages): each program attends one
-    ``[block_q * G, hd]`` query tile to one physical page, chasing the
-    scalar-prefetched page table over prefix AND suffix pages with the
-    causal mask applied inside the tile — so a request that shares its first
-    ``prefix_len`` tokens reads the prefix KV another request wrote, without
-    ever materializing a contiguous copy. ``block_q`` is clamped to divide S
-    at a multiple of 8.
+    The grid is (KV, S / block_q, n_blocks): each program attends one
+    ``[block_q * G, hd]`` query tile to one KV block of ``pages_per_block``
+    physical pages (:func:`_append_tiling`), chasing the page table over
+    prefix AND suffix pages with the causal mask applied inside the tile —
+    so a request that shares its first ``prefix_len`` tokens reads the
+    prefix KV another request wrote, without ever materializing a
+    contiguous copy. Each tile walks only the blocks up to its causal
+    frontier (:func:`append_walk` counts them). ``block_q`` is clamped to
+    divide S at a multiple of 8.
     """
     S, H, hd = q.shape
     _, KV, page_size, _ = k_arena.shape
@@ -330,9 +415,7 @@ def paged_append_attention_pallas(q, k_arena, v_arena, page_table, lens, *,
         raise ValueError(
             f"suffix length {S} must be padded to a multiple of 8 "
             "(VPU/MXU sublane layout)")
-    block_q = min(block_q, S)
-    while S % block_q:
-        block_q -= 8
+    block_q, ppb, n_blocks = _append_tiling(S, n_pages, page_size, block_q)
     n_qc = S // block_q
     scale = 1.0 / (hd ** 0.5)
 
@@ -341,24 +424,31 @@ def paged_append_attention_pallas(q, k_arena, v_arena, page_table, lens, *,
     qg = (q.reshape(S, KV, G, hd).transpose(1, 0, 2, 3)
           .reshape(KV, n_qc, block_q * G, hd))
     lens = lens.astype(jnp.int32)
-    page_table = page_table.astype(jnp.int32)
+    # a last block that overhangs the table reads page 0 there: its
+    # positions lie past total_len and mask out
+    page_table = jnp.pad(page_table.astype(jnp.int32),
+                         (0, n_blocks * ppb - n_pages))
+    tiles, bases = _append_walk_steps(lens[0], lens[1], n_qc, n_blocks,
+                                      block_q, ppb, page_size)
 
+    def page_spec(p):
+        def index(h, c, j, pt, lens, tiles, bases):
+            return (pt[bases[c * n_blocks + j] + p], h, 0, 0)
+        return pl.BlockSpec((None, None, page_size, hd), index)
+
+    pages = [page_spec(p) for p in range(ppb)]
     kernel = functools.partial(_paged_append_attn_kernel,
-                               page_size=page_size, block_q=block_q,
-                               group=G, scale=scale)
+                               pages_per_block=ppb, page_size=page_size,
+                               block_q=block_q, group=G, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                       # page table, lens
-        grid=(n_qc, KV, n_pages),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q * G, hd),
-                         lambda c, h, i, pt, lens: (h, c, 0, 0)),
-            pl.BlockSpec((None, None, page_size, hd),
-                         lambda c, h, i, pt, lens: (pt[i], h, 0, 0)),
-            pl.BlockSpec((None, None, page_size, hd),
-                         lambda c, h, i, pt, lens: (pt[i], h, 0, 0)),
-        ],
+        num_scalar_prefetch=4,           # page table, lens, the walk's steps
+        grid=(KV, n_qc, n_blocks),
+        in_specs=[pl.BlockSpec((None, None, block_q * G, hd),
+                               lambda h, c, j, pt, lens, tiles, bases:
+                               (h, tiles[c], 0, 0)),
+                  *pages, *pages],
         out_specs=pl.BlockSpec((None, None, block_q * G, hd),
-                               lambda c, h, i, pt, lens: (h, c, 0, 0)),
+                               lambda h, c, j, *_: (h, c, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q * G, 1), jnp.float32),
             pltpu.VMEM((block_q * G, 1), jnp.float32),
@@ -370,6 +460,6 @@ def paged_append_attention_pallas(q, k_arena, v_arena, page_table, lens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KV, n_qc, block_q * G, hd), q.dtype),
         interpret=interpret,
-    )(page_table, lens, qg, k_arena, v_arena)
+    )(page_table, lens, tiles, bases, qg, *[k_arena] * ppb, *[v_arena] * ppb)
     return (out.reshape(KV, n_qc, block_q, G, hd)
             .transpose(1, 2, 0, 3, 4).reshape(S, H, hd))

@@ -198,6 +198,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.tracing import span
 from repro.data.tokenizer import ByteTokenizer
+from repro.kernels.decode_attention.kernel import append_walk
 from repro.models.api import Model, build_model
 from repro.models.pdefs import is_pdef
 from repro.serving.paging import (
@@ -435,6 +436,10 @@ class ServingEngine:
         self.prefix_hits = 0      # engine-lifetime prefix-cache counters
         self.prefix_misses = 0
         self.prefix_tokens_shared = 0
+        # (query tile, KV block) pairs of one KV head in one layer that the
+        # paged append kernel computes, and the pairs in its static grid
+        self.append_blocks_walked = 0
+        self.append_blocks_grid = 0
         self.preemptions = 0      # residents reclaimed via preempt()
         self.dead = False         # crashed and not yet restarted
         self.engine_generation = 0  # bumped on every restart()
@@ -779,6 +784,8 @@ class ServingEngine:
             suffix = enc[prefix_len:]
             pad_len = self._pad_bucket(len(suffix))
             tokens, _ = self.tok.pad_batch([suffix], pad_len)
+            walked, grid = self._count_append(prefix_len, L, pad_len)
+            sp.set(append_blocks_walked=walked, append_blocks_grid=grid)
             logits, self._cache = self._prefill_paged(
                 self.params, self._cache, jnp.asarray(tokens),
                 jnp.int32(len(suffix)), jnp.int32(prefix_len),
@@ -863,6 +870,16 @@ class ServingEngine:
             sp.set(finished=len(done))
         return done
 
+    def _count_append(self, prefix_len: int, total_len: int, pad: int):
+        """Add one paged prefill's append-kernel walk (:func:`append_walk`
+        over a ``pad``-token suffix) to the engine's counters; returns
+        ``(walked, grid)`` for the caller's span. Host arithmetic only."""
+        walked, grid = append_walk(prefix_len, total_len, pad,
+                                   self.pages_per_slot, self.page_size)
+        self.append_blocks_walked += walked
+        self.append_blocks_grid += grid
+        return walked, grid
+
     def _pick_chunk(self, n_decode: int):
         """Budget policy: which mid-prefill resident advances this step,
         and by how many tokens. Highest priority first (interactive SLO
@@ -925,11 +942,15 @@ class ServingEngine:
                     ctoks, _ = self.tok.pad_batch([cs.enc[lo:lo + clen]],
                                                   self._chunk_pad)
                     finishing = lo + clen >= cs.prompt_tokens
+                    walked, grid = self._count_append(lo, lo + clen,
+                                                      self._chunk_pad)
                     sp.set(step=step, kind="fused" if dec else "prefill",
                            decode_rows=len(dec), chunk_rid=cs.req_id,
                            chunk_tokens=clen,
                            first_chunk=int(lo == cs.prefix_tokens),
-                           final_chunk=int(finishing))
+                           final_chunk=int(finishing),
+                           append_blocks_walked=walked,
+                           append_blocks_grid=grid)
                 else:
                     sp.set(step=step, kind="decode", decode_rows=len(dec))
                 # the host-to-device copies in a span of their own, so that

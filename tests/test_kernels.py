@@ -7,7 +7,7 @@ import pytest
 
 from repro.kernels.decode_attention import ops as da_ops
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_pallas, paged_append_attention_pallas,
+    append_walk, decode_attention_pallas, paged_append_attention_pallas,
     paged_decode_attention_pallas,
 )
 from repro.kernels.decode_attention.ref import (
@@ -206,12 +206,17 @@ def _append_case(P, ps, KV, hd, n_pages, seed=0):
     (14, 2, 64, 16, 96, 0, 96, 128),    # full prefill (no prefix), clamp bq
     (8, 4, 128, 8, 32, 40, 7, 32),      # long prefix, tiny suffix + padding
     (4, 4, 64, 32, 40, 32, 40, 128),    # MHA, page-aligned prefix, bq->40
+    # multi-page KV blocks (512 tokens: 32 pages of 16, 4 of 128)
+    (8, 2, 64, 16, 64, 500, 40, 32),    # unaligned prefix, suffix crosses
+    (14, 2, 64, 16, 256, 3584, 48, 32), # prefix hit: most tiles padding
+    (8, 2, 64, 16, 48, 10, 30, 16),     # table shorter than one block
+    (8, 1, 128, 128, 256, 300, 200, 128),  # page size 128, ragged table
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_append_matches_ref(H, KV, hd, ps, S, prefix, suffix, block_q,
                                   dtype):
-    P = 24
     n_pages = -(-(prefix + suffix) // ps) + 1
+    P = max(24, n_pages + 1)
     k_arena, v_arena, pt = _append_case(P, ps, KV, hd, n_pages)
     k_arena = k_arena.astype(dtype)
     v_arena = v_arena.astype(dtype)
@@ -279,6 +284,70 @@ def test_paged_append_causal_and_stale_page_masking():
     v2 = v2.at[pt[2]].set(-555.0)
     out2 = paged_append_attention_pallas(q, k2, v2, pt, lens)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
+
+
+def test_paged_append_never_reads_past_a_tiles_frontier():
+    """A query tile reads no key past its last valid query: per tile, NaN
+    in every position from its frontier on (later suffix tokens, the pages
+    past total_len, whole blocks past it), in the trash page (the table's
+    tail and the kernel's table padding point there) leaves that tile's
+    rows as they were. Tiles wholly past total_len (chunk padding) read
+    nothing, so their rows stay zero with every key NaN. NaN, not a large
+    finite value: a masked value row that reached p @ v unzeroed would
+    leak 0 * NaN."""
+    H, KV, hd, ps, block_q = 8, 2, 64, 16, 32
+    prefix, suffix, S, n_pages = 500, 100, 192, 70
+    total = prefix + suffix
+    k_arena, v_arena, pt = _append_case(96, ps, KV, hd, n_pages, seed=11)
+    pt = pt.at[-(-total // ps):].set(0)       # unused table tail -> trash
+    q = jax.random.normal(jax.random.PRNGKey(4), (S, H, hd))
+    lens = jnp.asarray([prefix, total], jnp.int32)
+    clean = np.asarray(paged_append_attention_pallas(
+        q, k_arena, v_arena, pt, lens, block_q=block_q))
+    assert np.isfinite(clean).all()
+    pos = np.arange(n_pages * ps)
+    pages, offs = np.asarray(pt)[pos // ps], pos % ps
+    for c in range(S // block_q):
+        first = prefix + c * block_q
+        frontier = min(first + block_q, total) if first < total else 0
+        dead = pos >= frontier
+        k2 = k_arena.at[0].set(jnp.nan).at[pages[dead], :, offs[dead]].set(
+            jnp.nan)
+        v2 = v_arena.at[0].set(jnp.nan).at[pages[dead], :, offs[dead]].set(
+            jnp.nan)
+        out = np.asarray(paged_append_attention_pallas(
+            q, k2, v2, pt, lens, block_q=block_q))
+        rows = slice(c * block_q, (c + 1) * block_q)
+        np.testing.assert_allclose(out[rows], clean[rows], atol=1e-6)
+        if not frontier:
+            assert (out[rows] == 0).all()
+
+
+def _walk_brute(prefix, total, chunk, n_pages, ps, block_q=128, bt=512):
+    """(tile, block) pairs holding at least one valid (query, key) pair:
+    query position < total and key position <= query position."""
+    walked = 0
+    for c in range(chunk // block_q):
+        qpos = prefix + c * block_q + np.arange(block_q)
+        qpos = qpos[qpos < total]
+        for b in range(-(-n_pages * ps // bt)):
+            kpos = b * bt + np.arange(bt)
+            walked += bool((kpos[None, :] <= qpos[:, None]).any())
+    return walked
+
+
+@pytest.mark.parametrize("prefix,total,n_pages", [
+    (3584, 3632, 256),      # edge prefix hit: 48-token question
+    (0, 2048, 256),         # edge miss, first chunk
+    (2048, 3620, 256),      # edge miss, final chunk
+    (6144, 8192, 1024),     # cloud, a middle chunk of a 9k context
+    (8192, 9017, 1024),     # cloud, a final chunk
+])
+def test_append_walk_counts_the_live_pairs(prefix, total, n_pages):
+    chunk, ps = 2048, 16
+    walked, grid = append_walk(prefix, total, chunk, n_pages, ps)
+    assert walked == _walk_brute(prefix, total, chunk, n_pages, ps)
+    assert grid == (chunk // 128) * (n_pages // 32)
 
 
 @pytest.mark.parametrize("N,D,k,block_n,n_valid", [

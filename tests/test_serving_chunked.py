@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.core.clock import VirtualClock
+from repro.kernels.decode_attention.kernel import append_walk
 from repro.serving.engine import EngineError, Request, make_edge_engine
 from repro.serving.scheduler import TierScheduler
 
@@ -126,6 +127,28 @@ def test_admission_is_async_and_first_token_deferred():
     assert s.pending is not None           # first token sampled...
     assert s.first_token_at is not None    # ...and stamped, at final chunk
     assert eng.prefill_tokens - p0 == s.prompt_tokens
+    eng.preempt(rid)
+    eng.assert_quiescent()
+
+
+def test_append_walk_counters_rise_by_one_chunk():
+    """Each chunk step adds the append kernel's walk for that chunk
+    (:func:`append_walk`) to the engine's two counters: host arithmetic
+    the CPU path counts as the chip path would."""
+    eng = budget_engine(max_batch=2)
+    rid = eng.admit(Request(LONG, max_new_tokens=4))
+    s = next(s for s in eng._slots if s is not None and s.req_id == rid)
+    for _ in range(2):
+        lo = s.prefill_done
+        clen = min(eng.prefill_chunk, s.prompt_tokens - lo)
+        want = append_walk(lo, lo + clen, eng._chunk_pad, eng.pages_per_slot,
+                           eng.page_size)
+        w0, g0 = eng.append_blocks_walked, eng.append_blocks_grid
+        eng.dispatch()
+        eng.collect()
+        assert (eng.append_blocks_walked - w0,
+                eng.append_blocks_grid - g0) == want
+        assert 0 < want[0] <= want[1]
     eng.preempt(rid)
     eng.assert_quiescent()
 

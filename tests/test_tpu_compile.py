@@ -96,6 +96,21 @@ def test_paged_append_compiles(spec, width, page_size):
     assert "paged_append_attention" in text
 
 
+@pytest.mark.parametrize("width,n_pages", [("qwen2-0.5b", 256),
+                                           ("qwen2-72b-tp8", 1024)])
+def test_paged_append_compiles_at_served_size(spec, width, n_pages):
+    """A served chunk (2,048 tokens, 16-token pages) against each tier's
+    page table: the multi-page KV blocks' working set must fit the
+    kernel's VMEM at both widths."""
+    H, KV, hd = WIDTHS[width]
+    arena = spec((n_pages + 1, KV, 16, hd))
+    text = _compile_kernel(
+        functools.partial(paged_append_attention_pallas, interpret=False),
+        spec((2048, H, hd)), arena, arena, spec((n_pages,), jnp.int32),
+        spec((2,), jnp.int32))
+    assert "paged_append_attention" in text
+
+
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_contiguous_decode_compiles(spec, width):
     H, KV, hd = WIDTHS[width]

@@ -138,11 +138,15 @@ def test_engine_spans_every_step_under_the_scheduler(tmp_path):
         if att["kind"] != "decode":
             rid = att["chunk_rid"]
             assert rid in admits and att["chunk_tokens"] > 0
+            assert 0 < att["append_blocks_walked"] <= att["append_blocks_grid"]
             if att["first_chunk"]:
                 first[rid] = recs[d]
             if att["final_chunk"]:
                 final[rid] = recs[d]
     assert set(first) == set(final) == set(admits)
+    chunks = [recs[d][4] for d in dispatches if recs[d][4]["kind"] != "decode"]
+    assert sum(a["append_blocks_walked"] for a in chunks) == \
+        eng.append_blocks_walked
     for rid, d in first.items():
         assert admits[rid][2] <= d[1]         # admitted before it launched
     landed = [recs[i][4]["first_token_rid"]
