@@ -3,13 +3,13 @@
 After the window, a sample of the requests the timed path finished, drawn
 from the seed, always holding the one with the most served tokens, a
 prefix-cache hit and a prompt that took more than one prefill chunk where
-the window has them, is run through the plain float32 reference
-(:mod:`chipbench.reference`) once each: prompt plus served tokens. For
-every served token, the gap by which the reference's logit of that token
-lies below the reference's best logit at that position is read. Greedy
-decoding serves the argmax of the program's own logits, so a sound program
-only loses to rounding near ties; a wrong page, mask, position or token
-loses by whole logits.
+the window has them, is run through the plain float32 reference of the
+configuration's architecture (its module's ``final_states``) once each:
+prompt plus served tokens. For every served token, the gap by which the
+reference's logit of that token lies below the reference's best logit at
+that position is read. Greedy decoding serves the argmax of the program's
+own logits, so a sound program only loses to rounding near ties; a wrong
+page, mask, position or token loses by whole logits.
 
 ``readings`` reduces the gaps to the numbers a configuration's ``check``
 may name; ``judge`` holds them to its limits and is the one verdict of a
@@ -67,27 +67,27 @@ def _rows(picks) -> Tuple[list, list]:
     return rows, positions
 
 
-def served_gaps(seed: int, model: dict, init: dict,
+def served_gaps(arch, seed: int, model: dict, init: dict,
                 picks) -> List[np.ndarray]:
     """Per request, per served token: the reference's best logit minus its
-    logit of the served token."""
+    logit of the served token. ``arch`` is the configuration's module
+    (``chipbench.run.arch``)."""
     rows, positions = _rows(picks)
-    states, top = reference.final_states(seed, model, init, rows)
+    states, top = arch.final_states(seed, model, init, rows)
     (gaps,) = reference.token_gaps(states, top, positions,
                                    [r.tokens for r in picks])
     return gaps
 
 
-def control_gaps(seed: int, model: dict, init: dict, picks,
+def control_gaps(arch, seed: int, model: dict, init: dict, picks,
                  quant: str = "int8"
                  ) -> Tuple[List[np.ndarray], List[np.ndarray]]:
     """Per request: the gaps of the served tokens and of the control's
     first-ranked tokens, both against the float32 reference, at the same
     positions."""
     rows, positions = _rows(picks)
-    qstates, qtop = reference.final_states(seed, model, init, rows,
-                                           quant=quant)
-    states, top = reference.final_states(seed, model, init, rows)
+    qstates, qtop = arch.final_states(seed, model, init, rows, quant=quant)
+    states, top = arch.final_states(seed, model, init, rows)
     served, ctrl = reference.token_gaps(states, top, positions,
                                         [r.tokens for r in picks],
                                         control=(qstates, qtop))

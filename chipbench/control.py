@@ -53,7 +53,8 @@ def main() -> int:
             seen["request_means"] = [float(g.mean()) for g in gaps if g.size]
             if seed in control and picks:
                 _, ctrl = check.control_gaps(
-                    seed, config["model"], config["init"], picks)
+                    R.arch(config), seed, config["model"], config["init"],
+                    picks)
                 read = check.readings(ctrl, picks)
                 ok, _ = check.judge(read, config["check"])
                 seen["control_readings"] = read

@@ -1,4 +1,6 @@
-"""Operations and bytes the algorithm needs, from shapes and token counts.
+"""Operations and bytes the algorithm needs, from shapes and token counts:
+the yardstick of the dense Qwen2 architecture
+(``chipbench/archs/Qwen2ForCausalLM.py``).
 
 These count the work, not what an implementation walks: attention over the
 live tokens of each row (not ``max_seq`` pages), each needed key and value

@@ -4,16 +4,15 @@ and the head for each decode row and each prefill chunk, over window times
 the chip's bf16 peak, in %."""
 from _common import decode_context, prefill_work
 
-from chipbench import flops
-
 
 def read(run, name):
     if not run.peaks:
         return None
     rows, live = decode_context(run)
     computed, attended, _ = prefill_work(run)
-    att = (flops.decode_attention(run.dims, rows, live)[0]
-           + flops.append_attention(run.dims, computed, attended, 0)[0])
-    total = flops.model_step(run.dims, rows + computed,
-                             rows + run.delta["prefill_chunks"], att)
+    a = run.arch
+    att = (a.decode_attention(run.dims, rows, live)[0]
+           + a.append_attention(run.dims, computed, attended, 0)[0])
+    total = a.model_step(run.dims, rows + computed,
+                         rows + run.delta["prefill_chunks"], att)
     return 100.0 * total / (run.window_s * run.peaks["bf16_flops"])

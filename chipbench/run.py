@@ -8,8 +8,10 @@ from the repository root. The cell is an entry of ``workloads`` in
 ``BENCHMARK.json``; its configuration file, its traffic mix
 (``chipbench/traffic/<traffic>.json``) and the reader of each of its metrics
 (``chipbench/metrics/<metric>.py``, or ``<prefix>.py`` for a metric named
-``<prefix>.<suffix>``) are found by name, so a new cell or metric is new
-files and new entries only.
+``<prefix>.<suffix>``) and its configuration's architecture
+(``chipbench/archs/<class>.py``, the class the configuration's
+``model.architectures`` names) are found by name, so a new cell, metric or
+model is new files and new entries only.
 
 A run: refuse anything but a TPU with published peaks (``peaks.py``) and
 enough chips; turn on JAX's persistent compilation cache in the checkout;
@@ -74,7 +76,8 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(root / configs[w["config"]]["file"])
-    traffic = load_json(HERE / "traffic" / f"{w['traffic']}.json")
+    traffic = load_json(root / "chipbench" / "traffic"
+                        / f"{w['traffic']}.json")
 
     def mine(metrics):
         return [m for m in metrics
@@ -99,6 +102,33 @@ def reader(metric: str):
             spec.loader.exec_module(mod)
             return mod.read
     raise BenchError(f"no reader for metric {metric!r} under {d}")
+
+
+def arch(config: dict):
+    """The architecture module of a configuration, from its own file:
+    ``chipbench/archs/<class>.py`` for the published class name in
+    ``config["model"]["architectures"][0]``. It gives
+
+    - ``dims(model)``: the sizes this chip holds, from the ``model`` group;
+    - ``program_config(config, dims)``: the program's ``ModelConfig``;
+    - ``program_params(seed, dims, init)``: every weight, in the program's
+      layout and served type, from one jitted call on the device;
+    - ``final_states(seed, model, init, rows, quant=None)``: the plain
+      float32 reference's final normed states of each token row and its top
+      weights, with the head under ``"head"``; ``quant="int8"`` gives the
+      control;
+    - the yardstick: ``layer_matmul_params``, ``kv_bytes_per_token``,
+      ``decode_attention``, ``append_attention``, ``model_step``."""
+    name = config["model"]["architectures"][0]
+    path = HERE / "archs" / f"{name}.py"
+    if not path.exists():
+        raise BenchError(f"no architecture module for {name!r}: {path} "
+                         f"does not exist")
+    spec = importlib.util.spec_from_file_location(f"chipbench_arch_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 # ---------------------------------------------------------------------------
@@ -130,19 +160,11 @@ def enable_compile_cache() -> None:
 # ---------------------------------------------------------------------------
 
 def model_config(config: dict):
-    from chipbench.reference import model_dims
-    from repro.configs.base import ModelConfig
-    m = config["model"]
-    dims = model_dims(m)
-    cfg = ModelConfig(
-        arch_id=config["name"], family="dense", n_layers=dims["layers"],
-        d_model=dims["d"], n_heads=dims["heads"],
-        n_kv_heads=dims["kv_heads"], d_ff=dims["ffn"], vocab=dims["vocab"],
-        head_dim=dims["head_dim"], qkv_bias=bool(m["qkv_bias"]),
-        rope_theta=float(m["rope_theta"]), rms_eps=float(m["rms_norm_eps"]),
-        tie_embeddings=dims["tied"], dtype=m["torch_dtype"], remat=False,
-        source=config["source"])
-    return cfg, dims
+    """The program's ``ModelConfig`` and the sizes this chip holds, from the
+    configuration's architecture module."""
+    a = arch(config)
+    dims = a.dims(config["model"])
+    return a.program_config(config, dims), dims
 
 
 def build_engine(config: dict, cfg, params):
@@ -299,6 +321,7 @@ class RunData:
     """What the metric readers read."""
     cell: dict
     dims: dict
+    arch: object                 # the configuration's module (``arch``)
     peaks: dict
     window_s: float
     delta: Dict[str, float]
@@ -330,18 +353,19 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     test break the timed path (``after_engine(eng)``) and the control read
     the compared requests (``after_check(picks, gaps)``)."""
     import jax
-    from chipbench import check, weights
+    from chipbench import check
     from chipbench.generator import Generator
     from repro.serving.engine import EngineError
     from repro.serving.scheduler import TierScheduler
 
     config, mix = cell["config"], cell["traffic"]
     cfg, dims = model_config(config)
+    model_arch = arch(config)
     device = device if device is not None else jax.devices()[0]
     hooks = hooks or {}
 
     # ---- set-up: weights, engine, warm programs, lead-in ---------------
-    params = weights.program_params(seed, dims, config["init"])
+    params = model_arch.program_params(seed, dims, config["init"])
     jax.block_until_ready(params)
     eng = build_engine(config, cfg, params)
     eng.warmup()
@@ -398,8 +422,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     stats = device.memory_stats() or {}
     memory_peak = int(stats.get("peak_bytes_in_use", 0))
 
-    data = RunData(cell=cell, dims=dims, peaks=peaks or {},
-                   window_s=window_s,
+    data = RunData(cell=cell, dims=dims, arch=model_arch,
+                   peaks=peaks or {}, window_s=window_s,
                    delta={k: c1[k] - c0[k] for k in c0}, recs=win,
                    all_recs=list(drv.recs.values()), window_t=(t0, t1))
     data.give_up = time.perf_counter()
@@ -456,8 +480,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     del sched, drv, eng, params
     gc.collect()
     t_ref = time.perf_counter()
-    gaps = (check.served_gaps(seed, config["model"], config["init"], picks)
-            if picks else [])
+    gaps = (check.served_gaps(model_arch, seed, config["model"],
+                              config["init"], picks) if picks else [])
     ref_s = time.perf_counter() - t_ref
     if "after_check" in hooks:
         hooks["after_check"](picks, gaps)

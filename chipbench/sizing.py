@@ -80,13 +80,14 @@ def analyse(config: dict, pages: int) -> dict:
 
 def main() -> int:
     import json
-    from chipbench.reference import model_dims
-    from chipbench import flops
+    from chipbench.run import arch
     name = sys.argv[1]
     hbm = float(sys.argv[2]) if len(sys.argv) > 2 else 15.0
     config = json.load(open(ROOT / "chipbench" / "configs" / f"{name}.json"))
-    dims = model_dims(config["model"])
-    page = flops.kv_bytes_per_token(dims) * config["engine"]["page_size"]
+    model_arch = arch(config)
+    dims = model_arch.dims(config["model"])
+    page = (model_arch.kv_bytes_per_token(dims)
+            * config["engine"]["page_size"])
     p1, p2 = 1024, 4096
     a1, a2 = analyse(config, p1), analyse(config, p2)
     weights = a1["decode"]["args"] - (p1 + 1) * page

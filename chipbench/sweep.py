@@ -36,7 +36,6 @@ def main() -> int:
     ap.add_argument("--rates", required=True)
     args = ap.parse_args()
     import jax
-    from chipbench import weights
     from chipbench.generator import Generator
     from repro.serving.scheduler import TierScheduler
 
@@ -45,7 +44,7 @@ def main() -> int:
     R.enable_compile_cache()
     config, mix = cell["config"], cell["traffic"]
     cfg, dims = R.model_config(config)
-    params = weights.program_params(args.seed, dims, config["init"])
+    params = R.arch(config).program_params(args.seed, dims, config["init"])
     eng = R.build_engine(config, cfg, params)
     eng.warmup()
     drv = R.Driver(TierScheduler({R.TIER: eng}), eng, spans=False)

@@ -13,8 +13,8 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from chipbench import check, weights
-from chipbench.run import build_engine, model_config
+from chipbench import check
+from chipbench.run import arch, build_engine, model_config
 from chipbench.tests._tiny import tiny_cell
 
 # toy widths: 4 layers of d 128, vocab 8,192; logits' spread ~3 as in the
@@ -34,8 +34,9 @@ def test_control_reads_worse_than_the_program(seed):
     config["model"].update(MODEL)
     config["init"]["embed_std"] = 3.0 / np.sqrt(128)
     cfg, dims = model_config(config)
-    eng = build_engine(config, cfg, weights.program_params(seed, dims,
-                                                           config["init"]))
+    model_arch = arch(config)
+    eng = build_engine(config, cfg, model_arch.program_params(
+        seed, dims, config["init"]))
     sched = TierScheduler({"serve": eng})
     rng = np.random.default_rng(seed)
     ctx = rng.integers(259, dims["vocab"], 96).tolist()
@@ -47,7 +48,7 @@ def test_control_reads_worse_than_the_program(seed):
     done = sched.drain()
     picks = [SimpleNamespace(prompt=c.request.prompt_ids,
                              tokens=c.token_ids) for c in done]
-    served, ctrl = check.control_gaps(seed, config["model"],
+    served, ctrl = check.control_gaps(model_arch, seed, config["model"],
                                       config["init"], picks)
     sound, control = check.readings(served, picks), check.readings(ctrl,
                                                                   picks)

@@ -10,7 +10,7 @@ import pytest
 
 from chipbench import run as R
 from chipbench.faults import alter_tokens, state_unchanged, wrong_prefix_pages
-from chipbench.tests._tiny import tiny_cell
+from chipbench.tests._tiny import tiny_cell, tiny_llm_only_cell
 
 SEED = 2 ** 31 + 2024
 
@@ -55,3 +55,20 @@ def test_saturated_mix_compares_resident_tokens(tmp_path):
     assert res["failed"] == 0
     assert "unfinished_requests" not in res["compared"]
     assert res["compared"]["served_tokens_compared"]["value"] > 0
+
+
+@pytest.mark.parametrize("fault,correct", [
+    (None, True),
+    (alter_tokens, False),
+    (state_unchanged, False),
+])
+def test_llm_only_sees_the_fault(fault, correct, tmp_path):
+    """The saturated LLM-only mix: no request drains, the residents' tokens
+    so far are compared, and a fault in the timed path still shows."""
+    hooks = {"after_engine": fault} if fault else {}
+    res = R.run(tiny_llm_only_cell(), SEED + 3, 1.0, False,
+                out_dir=tmp_path, hooks=hooks)
+    assert res["correct"] is correct, res["compared"]
+    assert res["attempted"] == 40 and res["failed"] == 0
+    assert res["compared"]["served_tokens_compared"]["value"] >= 100
+    assert "setup_s" in res["metrics"]

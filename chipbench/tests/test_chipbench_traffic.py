@@ -33,7 +33,7 @@ def flat(sched):
 
 @pytest.mark.parametrize("make,name", [
     (mix, "naive-rag-shared"), (unshared, "naive-rag-shared"),
-    (mix, "graph-rag")])
+    (mix, "graph-rag"), (mix, "llm-only")])
 def test_same_seed_same_schedule(make, name):
     a = Generator(make(name), BIG_SEED, 19008).schedule(20.0)
     b = Generator(make(name), BIG_SEED, 19008).schedule(20.0)
@@ -49,7 +49,8 @@ def test_same_seed_same_schedule(make, name):
             == sorted(x.topic or 0 for x in c.window))
 
 
-@pytest.mark.parametrize("name", ["naive-rag-shared", "graph-rag"])
+@pytest.mark.parametrize("name", ["naive-rag-shared", "graph-rag",
+                                  "llm-only"])
 def test_window_holds_rate_times_seconds(name):
     m = mix(name)
     s = Generator(m, 5, 151936).schedule(30.0)
@@ -83,6 +84,27 @@ def test_lengths_follow_table1(name, prompt_mean, prompt_tol, out):
     # stratified quantiles: only integer rounding separates the moments
     assert abs(olen.mean() - mu) < 0.5
     assert abs(olen.std() - sd) < 0.05 * sd
+
+
+def test_llm_only_follows_table1():
+    """Table 1's LLM-only arm: 16 +- 5 prompt tokens, all of them the
+    request's own, 27.2 +- 14.8 out; a burst of 40 fills the 32 rows before
+    a window of the cell's own length opens."""
+    m = mix("llm-only")
+    s = Generator(m, BIG_SEED, 151936).schedule(50.0)
+    assert len(s.window) == int(m["rate_per_s"] * 50.0) >= 100
+    plen = np.array([len(a.prompt) for a in s.window])
+    olen = np.array([a.max_new for a in s.window])
+    assert abs(plen.mean() - 16) <= 1 and plen.min() >= 1
+    assert abs(olen.mean() - 27.2) <= 2 and olen.min() >= 1
+    assert len(s.lead_in) == 40 and m["lead_in"]["fill"]
+    assert all(a.due == 0.0 for a in s.lead_in)
+    assert not s.warm and all(a.topic is None for a in s.window)
+    # no two requests share a prompt token sequence, nor a prefix page
+    prompts = [tuple(a.prompt) for a in s.lead_in + s.window]
+    assert len(set(prompts)) == len(prompts)
+    assert len({p[:16] for p in prompts if len(p) >= 16}) == sum(
+        len(p) >= 16 for p in prompts)
 
 
 def test_graph_rag_spread():
@@ -151,15 +173,16 @@ def test_quantiles_exact(spec, expect):
     assert quantiles(spec, 4).tolist() == expect
 
 
-def test_order_seed_fixes_the_order():
-    """A mix with ``order_seed`` sends every run seed the same sizes at the
-    same times; the run seed draws only the token ids."""
-    m = mix("graph-rag")
+@pytest.mark.parametrize("name", ["graph-rag", "naive-rag-shared"])
+def test_order_seed_fixes_the_order(name):
+    """A mix with ``order_seed`` sends every run seed the same sizes and
+    contexts at the same times; the run seed draws only the token ids."""
+    m = mix(name)
     assert "order_seed" in m
     a = Generator(m, BIG_SEED, 19008).schedule(50.0)
     b = Generator(m, BIG_SEED + 1, 19008).schedule(50.0)
-    sizes = [[(x.due, len(x.prompt), x.max_new) for x in s.lead_in + s.window]
-             for s in (a, b)]
+    sizes = [[(x.due, len(x.prompt), x.max_new, x.topic)
+              for x in s.lead_in + s.window] for s in (a, b)]
     assert sizes[0] == sizes[1]
     assert [x.prompt for x in a.window] != [x.prompt for x in b.window]
     m.pop("order_seed")
